@@ -12,6 +12,8 @@ import argparse
 import sys
 import traceback
 
+from repro.common.utils import enable_compile_cache
+
 
 def build_suites(n: int, smoke: bool = False) -> dict:
     from benchmarks import (
@@ -138,6 +140,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny corpora, skip hardware-bound suites")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n = 24 if args.smoke else (40 if args.fast else 80)
     suites = build_suites(n, smoke=args.smoke)

@@ -37,8 +37,8 @@ from repro.core.erarag import EraRAG
 from repro.core.store import ShardedVectorStore
 from repro.data.corpus import SyntheticCorpus
 from repro.embed.hashing import HashingEmbedder
-from repro.kernels.common import shard_map
 from repro.kernels.mips_topk.ops import merge_sharded_topk, mips_topk
+from repro.launch.mesh import local_data_mesh
 
 
 def main() -> None:
@@ -49,7 +49,7 @@ def main() -> None:
     rag.insert_docs(corpus.docs)
     ids, embs, _ = rag.graph.all_embeddings()
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = local_data_mesh(min_devices=1)
     k = 8
 
     # pad rows to device multiple, shard row-wise
@@ -59,7 +59,7 @@ def main() -> None:
     shard_rows = db.shape[0] // n_dev
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, None), P("data", None)),
         out_specs=(P("data", None, None), P("data", None, None)))
     def shard_search(q, db_shard):

@@ -1,6 +1,8 @@
 """Small shared utilities: PRNG discipline, pytree helpers, timers."""
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Any, Dict, Tuple
 
 import jax
@@ -39,6 +41,24 @@ def cast_tree(tree: Any, dtype) -> Any:
     return jax.tree.map(
         lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating)
         else x, tree)
+
+
+# the persistent compile cache's fallback home: fixed, so its entries
+# are found again by the next process (the path is part of the key)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself,
+    so no other directory is configured); otherwise the cache lives in
+    ``.jax_cache/`` at the repository root."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def timed(store: Dict[str, float], name: str):

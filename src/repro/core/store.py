@@ -1213,6 +1213,15 @@ class _BaseStore:
                 "scan_bits": self.scan_bits,
                 "scan_seed": self.scan_seed}
 
+    def device_buffers(self) -> Dict[str, Optional[jnp.ndarray]]:
+        """The device arrays the scans read, synced to the graph:
+        ``rows`` (the stacked ``[emb | flags]`` buffer), ``codes``
+        (the quantized plane, or None) and ``seq`` (the collective
+        path's global-sequence plane, or None)."""
+        self._refresh()
+        g = self._group
+        return {"rows": g.buf, "codes": g.codes, "seq": g.seq}
+
     def export_rows(self) -> Dict[str, np.ndarray]:
         """Alive rows in global-sequence order, captured to host: the
         replay source for the lifecycle ``Resharder``.  Returns

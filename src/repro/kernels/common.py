@@ -4,51 +4,11 @@ from __future__ import annotations
 import functools
 
 import jax
-import numpy as np
-
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # older releases: experimental namespace
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def shard_map_collective(f, mesh, in_specs, out_specs,
-                         check_rep: bool = False):
-    """``shard_map`` with version-portable axis-name plumbing.
-
-    Collective kernel entry points (e.g. the single-launch sharded
-    top-k scan) route through this shim instead of calling
-    ``shard_map`` directly: the replication-check kwarg was renamed
-    across jax releases (``check_rep`` -> ``check_vma``), and the
-    collectives inside the mapped programs (``all_gather`` + merge)
-    trip the strict checker on some versions, so it defaults off.
-    """
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=check_rep)
-    except TypeError:  # jax >= 0.6 renamed the kwarg
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check_rep)
 
 
 @functools.lru_cache(None)
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams`` constructor.
-
-    The class was renamed from ``TPUCompilerParams`` to
-    ``CompilerParams`` across JAX releases; resolve whichever this
-    installation provides.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
 
 
 def interpret_default() -> bool:
@@ -58,24 +18,3 @@ def interpret_default() -> bool:
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
-
-
-def round_up(a: int, b: int) -> int:
-    return cdiv(a, b) * b
-
-
-def pick_block(dim: int, preferred: int, align: int = 8) -> int:
-    """Largest block <= preferred that divides dim (after align rounding).
-
-    Dry-run shapes are always 128-aligned; tests use small odd shapes,
-    where we fall back to the whole (padded) dim.
-    """
-    if dim % preferred == 0:
-        return preferred
-    for b in range(min(preferred, dim), 0, -1):
-        if dim % b == 0 and b % align == 0:
-            return b
-    return dim
-
-
-POW2_32 = np.asarray([1 << i for i in range(32)], dtype=np.uint32)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 from repro.kernels.mips_topk.kernel import _NEG, _merge_topk
 
 
@@ -32,17 +32,20 @@ def _hamming_kernel(qc_ref, dbc_ref, out_d_ref, out_i_ref,
         vals_ref[...] = jnp.full_like(vals_ref, _NEG)
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
+    # the DB tile arrives word-major (w, bn): each word's XOR is a
+    # lane-dense (bq, bn) block instead of a (bq, bn, w) cube whose
+    # w-wide minor dim would pad to 128 lanes
     qc = qc_ref[...]                                # (bq, w) uint32
-    dbc = dbc_ref[...]                              # (bn, w) uint32
-    x = jnp.bitwise_xor(qc[:, None, :], dbc[None, :, :])
-    dist = jnp.sum(jax.lax.population_count(x).astype(jnp.int32),
-                   axis=-1)                         # (bq, bn)
+    dist = None
+    for j in range(w):
+        x = jnp.bitwise_xor(qc[:, j:j + 1], dbc_ref[j:j + 1, :])
+        c = jax.lax.population_count(x).astype(jnp.int32)
+        dist = c if dist is None else dist + c      # (bq, bn)
 
     base = i_n * bn
-    tile_idx = base + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)[:, 0]
-    scores = jnp.where((tile_idx < n)[None, :], -dist.astype(jnp.float32),
-                       _NEG)
-    nv, ni = _merge_topk(vals_ref[...], idx_ref[...], scores, tile_idx, k)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    scores = jnp.where(base + col < n, -dist.astype(jnp.float32), _NEG)
+    nv, ni = _merge_topk(vals_ref[...], idx_ref[...], scores, base, k)
     vals_ref[...] = nv
     idx_ref[...] = ni
 
@@ -65,16 +68,16 @@ def hamming_topk_pallas(qc: jnp.ndarray, dbc: jnp.ndarray, k: int, *,
     b_pad = cdiv(b, bq) * bq - b
     n_pad = cdiv(n, bn) * bn - n
     qc_p = jnp.pad(qc, ((0, b_pad), (0, 0)))
-    dbc_p = jnp.pad(dbc, ((0, n_pad), (0, 0)))
+    dbc_t = jnp.pad(dbc, ((0, n_pad), (0, 0))).T       # (w, n) word-major
     b_t = qc_p.shape[0] // bq
-    n_t = dbc_p.shape[0] // bn
+    n_t = dbc_t.shape[1] // bn
 
     out_d, out_i = pl.pallas_call(
         functools.partial(_hamming_kernel, k=k, bn=bn, n=n, n_n=n_t, w=w),
         grid=(b_t, n_t),
         in_specs=[
             pl.BlockSpec((bq, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
@@ -88,8 +91,8 @@ def hamming_topk_pallas(qc: jnp.ndarray, dbc: jnp.ndarray, k: int, *,
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qc_p, dbc_p)
+    )(qc_p, dbc_t)
     return out_d[:b], out_i[:b]

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 
 def _lsh_hash_kernel(v_ref, h_ref, out_ref, acc_ref, *, n_d: int, k: int):
@@ -30,20 +30,27 @@ def _lsh_hash_kernel(v_ref, h_ref, out_ref, acc_ref, *, n_d: int, k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # full f32 contraction: a bf16 pass would flip the sign bit of
+    # projections near zero, so codes would depend on the backend
     acc_ref[...] += jnp.dot(v_ref[...], h_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(i_d == n_d - 1)
     def _finalize():
+        # Mosaic reduces no unsigned integers: each word is summed in
+        # int32 (distinct powers of two, so the sum is the bitwise OR;
+        # bit 31 wraps to the sign bit) and bitcast to uint32 unchanged
         proj = acc_ref[...]                       # (bn, k_pad)
-        bits = (proj >= 0.0).astype(jnp.uint32)
-        bn, k_pad = bits.shape
-        n_words = k_pad // 32
-        bits = bits.reshape(bn, n_words, 32)
-        pow2 = (jnp.uint32(1) << jax.lax.broadcasted_iota(
-            jnp.uint32, (1, 1, 32), 2))
-        words = jnp.sum(bits * pow2, axis=-1, dtype=jnp.uint32)
-        out_ref[...] = words                      # (bn, n_words)
+        bits = (proj >= 0.0).astype(jnp.int32)
+        pow2 = jnp.left_shift(
+            jnp.int32(1), jax.lax.broadcasted_iota(jnp.int32, (1, 32), 1))
+        words = [jnp.sum(bits[:, 32 * j:32 * (j + 1)] * pow2, axis=1,
+                         keepdims=True)
+                 for j in range(bits.shape[1] // 32)]
+        words = words[0] if len(words) == 1 else \
+            jnp.concatenate(words, axis=1)
+        out_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d",
@@ -85,7 +92,7 @@ def lsh_hash_pallas(v: jnp.ndarray, h: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((v_p.shape[0], n_words),
                                        jnp.uint32),
         scratch_shapes=[pltpu.VMEM((bn, k_pad), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(v_p, h_p)

@@ -22,39 +22,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, tpu_compiler_params
+from repro.kernels.common import cdiv
 
 _NEG = -3.0e38  # python float: avoids capturing a traced constant
 
 
-def _merge_topk(run_vals, run_idx, scores, tile_idx, k: int):
+def _merge_topk(run_vals, run_idx, scores, base, k: int):
     """Fold (bq, bn) scores into running (bq, k) top-k. Returns new pair.
 
-    First-occurrence tie-breaking reproduces jax.lax.top_k semantics
-    because running entries (earlier global indices) sit left of the
-    score tile and tiles arrive in index order.
+    Column ``j`` of ``scores`` is global row ``base + j``.
+
+    First-occurrence tie-breaking reproduces jax.lax.top_k semantics:
+    running entries hold earlier global indices than the score tile
+    (tiles arrive in index order), so a tie between the two goes to the
+    running entry, and within either block to the lower position.
+
+    The k selection passes run as a ``fori_loop`` over the running and
+    tile blocks separately: no ``(bq, k + bn)`` concatenation, and only
+    one pass's ``(bq, bn)`` temporaries are live at a time, which keeps
+    the kernel inside VMEM for query tiles of 128 rows.
     """
-    bq = scores.shape[0]
-    comb_v = jnp.concatenate([run_vals, scores], axis=1)          # (bq, k+bn)
-    comb_i = jnp.concatenate(
-        [run_idx, jnp.broadcast_to(tile_idx[None, :],
-                                   (bq, tile_idx.shape[0]))], axis=1)
-    width = comb_v.shape[1]
-    col = jax.lax.broadcasted_iota(jnp.int32, (bq, width), 1)
-    new_v = []
-    new_i = []
-    for _ in range(k):
-        m = jnp.max(comb_v, axis=1, keepdims=True)                # (bq, 1)
-        is_max = comb_v == m
-        pos = jnp.min(jnp.where(is_max, col, width), axis=1,
-                      keepdims=True)                              # first max
-        sel = col == pos
-        chosen_i = jnp.sum(jnp.where(sel, comb_i, 0), axis=1)
-        new_v.append(m[:, 0])
-        new_i.append(chosen_i)
-        comb_v = jnp.where(sel, _NEG, comb_v)
-    return (jnp.stack(new_v, axis=1),
-            jnp.stack(new_i, axis=1).astype(jnp.int32))
+    bq, bn = scores.shape
+    col_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    col_n = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
+
+    def select(j, carry):
+        run_v, tile_v, out_v, out_i = carry
+        m_r = jnp.max(run_v, axis=1, keepdims=True)               # (bq, 1)
+        m_t = jnp.max(tile_v, axis=1, keepdims=True)
+        from_run = m_r >= m_t
+        pos_r = jnp.min(jnp.where(run_v == m_r, col_k, k), axis=1,
+                        keepdims=True)
+        pos_t = jnp.min(jnp.where(tile_v == m_t, col_n, bn), axis=1,
+                        keepdims=True)
+        sel_r = (col_k == pos_r) & from_run
+        sel_t = (col_n == pos_t) & ~from_run
+        idx_r = jnp.sum(jnp.where(sel_r, run_idx, 0), axis=1,
+                        keepdims=True)
+        here = col_k == j
+        out_v = jnp.where(here, jnp.maximum(m_r, m_t), out_v)
+        out_i = jnp.where(here, jnp.where(from_run, idx_r, base + pos_t),
+                          out_i)
+        return (jnp.where(sel_r, _NEG, run_v),
+                jnp.where(sel_t, _NEG, tile_v), out_v, out_i)
+
+    init = (run_vals, scores, jnp.full((bq, k), _NEG, jnp.float32),
+            jnp.zeros((bq, k), jnp.int32))
+    _, _, out_v, out_i = jax.lax.fori_loop(0, k, select, init)
+    return out_v, out_i
 
 
 def _mips_kernel(q_ref, db_ref, out_v_ref, out_i_ref,
@@ -72,17 +87,18 @@ def _mips_kernel(q_ref, db_ref, out_v_ref, out_i_ref,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # full f32 contraction: the default MXU precision rounds the
+    # operands to bf16, which reorders near-tied candidates
     acc_ref[...] += jnp.dot(q_ref[...], db_ref[...].T,
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(i_d == n_d - 1)
     def _merge():
         base = i_n * bn
-        tile_idx = base + jax.lax.broadcasted_iota(
-            jnp.int32, (bn, 1), 0)[:, 0]
-        scores = jnp.where((tile_idx < n)[None, :], acc_ref[...], _NEG)
-        nv, ni = _merge_topk(vals_ref[...], idx_ref[...], scores,
-                             tile_idx, k)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+        scores = jnp.where(base + col < n, acc_ref[...], _NEG)
+        nv, ni = _merge_topk(vals_ref[...], idx_ref[...], scores, base, k)
         vals_ref[...] = nv
         idx_ref[...] = ni
 
@@ -134,7 +150,7 @@ def mips_topk_pallas(q: jnp.ndarray, db: jnp.ndarray, k: int, *,
             pltpu.VMEM((bq, k), jnp.float32),
             pltpu.VMEM((bq, k), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(q_p, db_p)

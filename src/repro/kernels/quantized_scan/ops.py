@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.common import cdiv, on_tpu, shard_map_collective
+from repro.kernels.common import cdiv, on_tpu
 from repro.kernels.hamming_topk.ops import hamming_topk
 from repro.kernels.hamming_topk.ref import hamming_dist_ref
 from repro.kernels.lsh_hash.ops import lsh_hash
@@ -232,7 +232,8 @@ def _two_stage(q_aug: jnp.ndarray, q_codes: jnp.ndarray,
         dup = jnp.concatenate([jnp.zeros((1,), bool),
                                flat[1:] == flat[:-1]])
         sub = jnp.take(db, flat, axis=0)
-        scores = q_aug @ sub.T                   # (B, B*C) exact fp32
+        scores = jnp.matmul(q_aug, sub.T,        # (B, B*C) exact fp32
+                            precision=jax.lax.Precision.HIGHEST)
         cols = jnp.broadcast_to(flat[None, :], scores.shape)
         keep = jnp.take_along_axis(sel, cols, axis=1) & ~dup[None, :]
         scores = jnp.where(keep, scores, _DUP_PAD)
@@ -249,7 +250,8 @@ def _two_stage(q_aug: jnp.ndarray, q_codes: jnp.ndarray,
     uc = jnp.minimum(union, n - 1)               # clamp the padding
     cols = jnp.broadcast_to(uc[None, :], (b, u))
     sub = jnp.take(db, uc, axis=0)
-    scores = q_aug @ sub.T                       # (B, U) exact fp32
+    scores = jnp.matmul(q_aug, sub.T,            # (B, U) exact fp32
+                        precision=jax.lax.Precision.HIGHEST)
     keep = jnp.take_along_axis(sel, cols, axis=1) & valid[None, :]
     scores = jnp.where(keep, scores, _DUP_PAD)
     vals, ci = jax.lax.top_k(scores, k)
@@ -318,11 +320,11 @@ def _sharded_quantized_topk(q, db, codes, seq, planes, *, k_shard,
                                tiled=True)
         return mips_ops._merge_sharded_topk(v, s, k_out)
 
-    return shard_map_collective(
-        scan_gather_merge, mesh,
+    return jax.shard_map(
+        scan_gather_merge, mesh=mesh,
         in_specs=(P(None, None), P(None, None), P(lead, None, None),
                   P(lead, None, None), P(lead, None)),
-        out_specs=(P(None, None), P(None, None)))(
+        out_specs=(P(None, None), P(None, None)), check_vma=False)(
             q_aug, qc, db, codes, seq)
 
 

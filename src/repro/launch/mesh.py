@@ -8,19 +8,31 @@ jax import and only then calls this.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes.
+
+    The installed JAX builds Explicit axes by default, under which the
+    store's slice updates and gathers on a sharded stack would each
+    need their output sharding spelled out; with Auto axes XLA
+    propagates the stack's ``NamedSharding`` through them."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(n_devices: int | None = None, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def local_data_mesh(min_devices: int = 2,
@@ -35,4 +47,4 @@ def local_data_mesh(min_devices: int = 2,
     n = n_devices or n_avail
     if n_avail < max(min_devices, n):
         return None
-    return jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    return _auto_mesh((n,), ("data",), devices=jax.devices()[:n])

@@ -47,6 +47,23 @@ def n_blocks(cfg: LMConfig) -> int:
 
 def init_params(cfg: LMConfig, key, dtype=jnp.float32
                 ) -> Tuple[Params, Params]:
+    """Random parameters (and their logical-axes twin).
+
+    One jitted program draws every weight, so XLA fuses each f32 draw
+    with its cast: the peak is the ``dtype`` parameters themselves,
+    never an f32 copy of a stacked weight (at a 7B width the stacked
+    ``w_gate`` alone would be 4 GiB in f32 over 16 layers)."""
+    axes = {}
+
+    def build(k):
+        params, ax = _init_params(cfg, k, dtype)
+        axes.update(ax)
+        return params
+
+    return jax.jit(build)(key), axes
+
+
+def _init_params(cfg: LMConfig, key, dtype) -> Tuple[Params, Params]:
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     bs = block_size(cfg)
 

@@ -114,17 +114,17 @@ def test_shard_map_retrieval_exact():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.kernels.common import shard_map
+    from repro.launch.mesh import local_data_mesh
     from repro.kernels.mips_topk.ops import merge_sharded_topk, \
         mips_topk
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = local_data_mesh(min_devices=1)
     rng = np.random.default_rng(0)
     db = rng.standard_normal((64 * n_dev, 16)).astype(np.float32)
     q = rng.standard_normal((3, 16)).astype(np.float32)
     rows = db.shape[0] // n_dev
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(None, None), P("data", None)),
                        out_specs=(P("data", None, None),
                                   P("data", None, None)))
