@@ -24,7 +24,6 @@ def build_suites(n: int, smoke: bool = False) -> dict:
         initial_coverage,
         kernel_bench,
         live_serving,
-        obs_overhead,
         quantized_scan,
         query_batch,
         query_cache,
@@ -73,10 +72,6 @@ def build_suites(n: int, smoke: bool = False) -> dict:
         # migration; bitwise replay parity and old-epoch availability
         # are asserted (nonzero exit on trip)
         "live_serving": lambda: live_serving.run(n_docs=half),
-        # observability overhead gate: obs-off answers bitwise equal to
-        # obs-on, zero spans when off, schema-drift clean, and the
-        # traced query phase within the 10% QPS budget (all asserted)
-        "obs_overhead": lambda: obs_overhead.run(n_docs=half),
         "kernel_bench": kernel_bench.run,
         "roofline": roofline.run,
     }
@@ -121,14 +116,6 @@ def build_suites(n: int, smoke: bool = False) -> dict:
         suites["live_serving"] = lambda: live_serving.run(
             n_docs=24, queries_per_phase=3,
             latency_ratio_ceiling=500.0)
-        # parity / zero-span / schema asserts are scale-free and the
-        # 10% overhead budget is kept, but NOT at 24 docs — a tiny
-        # store makes the per-span fixed cost proportionally large
-        # (measured ~9% vs ~2% at 40 docs), so this suite keeps its
-        # 40-doc corpus in smoke; still seconds-scale, still emits
-        # BENCH_obs.json
-        suites["obs_overhead"] = lambda: obs_overhead.run(
-            n_docs=40, reps=7)
     return suites
 
 

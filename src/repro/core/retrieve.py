@@ -21,7 +21,8 @@ Searches accept either store kind (``AnyStore``): the single-buffer
 ``VectorStore`` or the ``ShardedVectorStore`` whose row set is split
 over the data mesh axis — both return bitwise-identical hits, so every
 path above this module (EraRAG, RAGPipeline, benchmarks) is
-shard-agnostic.
+shard-agnostic.  The budgeted composition of the contexts runs under
+the store's tracer as the ``context`` span.
 """
 from __future__ import annotations
 
@@ -98,8 +99,9 @@ def collapsed_search_batch(graph, store: AnyStore, query_embs,
                            ) -> List[Retrieval]:
     tok = tokenizer or HashTokenizer()
     hits_b = store.search_batch(np.asarray(query_embs), k)
-    out = [_budgeted(graph, hits, token_budget, tok)
-           for hits in hits_b]
+    with store.tracer.span("context", n=len(hits_b)):
+        out = [_budgeted(graph, hits, token_budget, tok)
+               for hits in hits_b]
     for r in out:
         r.epoch = store.epoch
     return out
@@ -135,14 +137,15 @@ def adaptive_search_batch(graph, store: AnyStore, query_embs,
     rest_b = store.search_batch(q, k_rest, layer_filter=secondary) \
         if k_rest else [[] for _ in range(n_q)]
     out: List[Retrieval] = []
-    for prim, rest in zip(prim_b, rest_b):
-        hits = prim + rest
-        # score ties between the two layer scans break on insertion
-        # seq (the kernel-side lowest-index rule): without it the
-        # budgeted context would depend on which layer was scanned
-        # first, making adaptive search order-sensitive
-        hits.sort(key=lambda h: (-h.score, h.seq))
-        out.append(_budgeted(graph, hits, token_budget, tok))
+    with store.tracer.span("context", n=n_q):
+        for prim, rest in zip(prim_b, rest_b):
+            hits = prim + rest
+            # score ties between the two layer scans break on insertion
+            # seq (the kernel-side lowest-index rule): without it the
+            # budgeted context would depend on which layer was scanned
+            # first, making adaptive search order-sensitive
+            hits.sort(key=lambda h: (-h.score, h.seq))
+            out.append(_budgeted(graph, hits, token_budget, tok))
     for r in out:
         r.epoch = store.epoch
     return out

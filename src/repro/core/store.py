@@ -315,40 +315,40 @@ def _pin(sharding) -> dict:
 
 @functools.lru_cache(maxsize=None)
 def _grow_buf_fn(sharding, pad_rows: int, dim: int):
-    def grow(buf):
+    def store_grow_rows(buf):
         pad_shape = buf.shape[:-2] + (pad_rows, buf.shape[-1])
         pad = jnp.zeros(pad_shape, jnp.float32) \
             .at[..., dim + _DEAD].set(1.0)
         return jnp.concatenate([buf, pad], axis=-2)
-    return jax.jit(grow, **_pin(sharding))
+    return jax.jit(store_grow_rows, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _grow_seq_fn(sharding, pad_rows: int):
-    def grow(seq):
+    def store_grow_seq(seq):
         pad = jnp.full(seq.shape[:-1] + (pad_rows,), int(_SEQ_PAD),
                        jnp.int32)
         return jnp.concatenate([seq, pad], axis=-1)
-    return jax.jit(grow, **_pin(sharding))
+    return jax.jit(store_grow_seq, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _write_rows_fn(sharding, flat2d: bool):
-    def write(buf, block, slot, row0):
+    def store_write_rows(buf, block, slot, row0):
         if flat2d:
             return jax.lax.dynamic_update_slice(buf, block, (row0, 0))
         return jax.lax.dynamic_update_slice(buf, block[None],
                                             (slot, row0, 0))
-    return jax.jit(write, **_pin(sharding))
+    return jax.jit(store_write_rows, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _mark_dead_fn(sharding, flat2d: bool, dim: int):
-    def mark(buf, rows, slot):
+    def store_mark_dead(buf, rows, slot):
         if flat2d:
             return buf.at[rows, dim + _DEAD].set(1.0)
         return buf.at[slot, rows, dim + _DEAD].set(1.0)
-    return jax.jit(mark, **_pin(sharding))
+    return jax.jit(store_mark_dead, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
@@ -356,48 +356,48 @@ def _compact_buf_fn(flat2d: bool, dim: int):
     # produces a STANDALONE compacted slice (the double buffer) — it is
     # swapped into the stack only at commit time, so queries dispatched
     # between refreshes never depend on this gather
-    def compacted(buf, keep, slot):
+    def store_compact_rows(buf, keep, slot):
         sl = buf if flat2d else buf[slot]
         out = jnp.zeros_like(sl).at[..., dim + _DEAD].set(1.0)
         return jax.lax.dynamic_update_slice(
             out, jnp.take(sl, keep, axis=0), (0, 0))
-    return jax.jit(compacted)
+    return jax.jit(store_compact_rows)
 
 
 @functools.lru_cache(maxsize=None)
 def _compact_seq_fn():
-    def compacted(seq, keep, slot):
+    def store_compact_seq(seq, keep, slot):
         sl = seq[slot]
         out = jnp.full_like(sl, int(_SEQ_PAD))
         return jax.lax.dynamic_update_slice(
             out, jnp.take(sl, keep, axis=0), (0,))
-    return jax.jit(compacted)
+    return jax.jit(store_compact_seq)
 
 
 @functools.lru_cache(maxsize=None)
 def _commit_buf_fn(sharding, flat2d: bool):
-    def commit(buf, new_slice, slot):
+    def store_commit_rows(buf, new_slice, slot):
         if flat2d:
             return new_slice
         return jax.lax.dynamic_update_slice(buf, new_slice[None],
                                             (slot, 0, 0))
-    return jax.jit(commit, **_pin(sharding))
+    return jax.jit(store_commit_rows, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _commit_seq_fn(sharding):
-    def commit(seq, new_slice, slot):
+    def store_commit_seq(seq, new_slice, slot):
         return jax.lax.dynamic_update_slice(seq, new_slice[None],
                                             (slot, 0))
-    return jax.jit(commit, **_pin(sharding))
+    return jax.jit(store_commit_seq, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _write_seq_fn(sharding):
-    def write(seq, block, slot, row0):
+    def store_write_seq(seq, block, slot, row0):
         return jax.lax.dynamic_update_slice(seq, block[None],
                                             (slot, row0))
-    return jax.jit(write, **_pin(sharding))
+    return jax.jit(store_write_seq, **_pin(sharding))
 
 
 # -- compressed code plane (two-stage quantized retrieval) ------------------
@@ -416,11 +416,11 @@ def _dead_coded(codes_slice: jnp.ndarray,
 
 @functools.lru_cache(maxsize=None)
 def _grow_codes_fn(sharding, pad_rows: int, spec: QuantSpec):
-    def grow(codes):
+    def store_grow_codes(codes):
         pad_shape = codes.shape[:-2] + (pad_rows, codes.shape[-1])
         pad = _dead_coded(jnp.zeros(pad_shape, jnp.uint32), spec)
         return jnp.concatenate([codes, pad], axis=-2)
-    return jax.jit(grow, **_pin(sharding))
+    return jax.jit(store_grow_codes, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
@@ -428,37 +428,37 @@ def _encode_write_fn(sharding, flat2d: bool, spec: QuantSpec):
     # rows are hashed ONCE, here, at append (or snapshot replay —
     # load_state funnels through the same write): the compressed plane
     # can never drift from the fp32 rows it mirrors
-    def write(codes, block, planes, slot, row0):
+    def store_write_codes(codes, block, planes, slot, row0):
         enc = encode_rows(block[:, :spec.dim], block[:, spec.dim:],
                           planes, spec)
         if flat2d:
             return jax.lax.dynamic_update_slice(codes, enc, (row0, 0))
         return jax.lax.dynamic_update_slice(codes, enc[None],
                                             (slot, row0, 0))
-    return jax.jit(write, **_pin(sharding))
+    return jax.jit(store_write_codes, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _mark_dead_codes_fn(sharding, flat2d: bool, spec: QuantSpec):
     lo, hi = spec.flag_group(_DEAD)
 
-    def mark(codes, rows, slot):
+    def store_mark_dead_codes(codes, rows, slot):
         if flat2d:
             return codes.at[rows, lo:hi].set(_CODE_DEAD)
         return codes.at[slot, rows, lo:hi].set(_CODE_DEAD)
-    return jax.jit(mark, **_pin(sharding))
+    return jax.jit(store_mark_dead_codes, **_pin(sharding))
 
 
 @functools.lru_cache(maxsize=None)
 def _compact_codes_fn(flat2d: bool, spec: QuantSpec):
     # codes ride the SAME keep index as the fp32 gather — the two
     # planes stay row-aligned by construction
-    def compacted(codes, keep, slot):
+    def store_compact_codes(codes, keep, slot):
         sl = codes if flat2d else codes[slot]
         out = _dead_coded(jnp.zeros_like(sl), spec)
         return jax.lax.dynamic_update_slice(
             out, jnp.take(sl, keep, axis=0), (0, 0))
-    return jax.jit(compacted)
+    return jax.jit(store_compact_codes)
 
 
 class _StackedBuffers:
@@ -1343,15 +1343,18 @@ class VectorStore(_BaseStore):
                     jnp.asarray(q), self._s.buf, k_eff,
                     _filter_bias(layer_filter))
         self._store_stats.kernel_launches += 1
-        vals = np.asarray(vals)
-        idx = np.asarray(idx)
-        out: List[List[Hit]] = []
-        for b in range(q.shape[0]):
-            out.append([
-                Hit(node_id=self._s.row_ids[int(r)], score=float(v),
-                    layer=int(self._s.row_layers[int(r)]),
-                    seq=int(self._s.row_seq[int(r)]))
-                for v, r in zip(vals[b], idx[b])])
+        # the host waits here for the scan dispatched above
+        with self.tracer.span("fetch"):
+            vals = np.asarray(vals)
+            idx = np.asarray(idx)
+        with self.tracer.span("hits"):
+            out: List[List[Hit]] = []
+            for b in range(q.shape[0]):
+                out.append([
+                    Hit(node_id=self._s.row_ids[int(r)], score=float(v),
+                        layer=int(self._s.row_layers[int(r)]),
+                        seq=int(self._s.row_seq[int(r)]))
+                    for v, r in zip(vals[b], idx[b])])
         self.query_hits[0] += sum(len(hits) for hits in out)
         return out
 
@@ -1569,17 +1572,19 @@ class ShardedVectorStore(_BaseStore):
                                          quantized=quant)
             if quant:
                 self._store_stats.quantized_scans += 1
-        mv = np.asarray(mv)
-        ms = np.asarray(ms)
-        out: List[List[Hit]] = []
-        for b in range(n_q):
-            hits: List[Hit] = []
-            for v, s in zip(mv[b], ms[b]):
-                nid, layer, shard = self._seq_map[int(s)]
-                self.query_hits[shard] += 1
-                hits.append(Hit(node_id=nid, score=float(v),
-                                layer=layer, seq=int(s)))
-            out.append(hits)
+        with self.tracer.span("fetch"):
+            mv = np.asarray(mv)
+            ms = np.asarray(ms)
+        with self.tracer.span("hits"):
+            out: List[List[Hit]] = []
+            for b in range(n_q):
+                hits: List[Hit] = []
+                for v, s in zip(mv[b], ms[b]):
+                    nid, layer, shard = self._seq_map[int(s)]
+                    self.query_hits[shard] += 1
+                    hits.append(Hit(node_id=nid, score=float(v),
+                                    layer=layer, seq=int(s)))
+                out.append(hits)
         return out
 
     def _loop_dispatch(self, q: np.ndarray, k_eff: int,
@@ -1612,15 +1617,16 @@ class ShardedVectorStore(_BaseStore):
                 pending.append((sh, k_s, v, i))
         val_blocks: List[np.ndarray] = []
         seq_blocks: List[np.ndarray] = []
-        for sh, k_s, v, i in pending:
-            v = np.asarray(v)
-            seqs = sh.seqs_at(np.asarray(i))
-            if k_s < k_eff:
-                padw = ((0, 0), (0, k_eff - k_s))
-                v = np.pad(v, padw, constant_values=_VAL_PAD)
-                seqs = np.pad(seqs, padw, constant_values=_SEQ_PAD)
-            val_blocks.append(v)
-            seq_blocks.append(seqs)
+        with self.tracer.span("fetch", shards=len(pending)):
+            for sh, k_s, v, i in pending:
+                v = np.asarray(v)
+                seqs = sh.seqs_at(np.asarray(i))
+                if k_s < k_eff:
+                    padw = ((0, 0), (0, k_eff - k_s))
+                    v = np.pad(v, padw, constant_values=_VAL_PAD)
+                    seqs = np.pad(seqs, padw, constant_values=_SEQ_PAD)
+                val_blocks.append(v)
+                seq_blocks.append(seqs)
         vals = jnp.asarray(np.stack(val_blocks))
         # int32 is exact: _renumber_seqs keeps every seq < _SEQ_LIMIT
         seqs = jnp.asarray(np.stack(seq_blocks).astype(np.int32))

@@ -4,9 +4,8 @@
 numeric key the report may surface, as dotted paths with list indices
 normalized to ``*``.  It is deliberately static (NOT derived from the
 dataclasses it mirrors) so the drift check in ``tests/test_obs.py``
-and ``benchmarks/obs_overhead.py`` fires the moment a new counter is
-added to a subsystem without being declared here — new telemetry
-cannot silently bypass the obs layer.
+fires the moment a new counter is added to a subsystem without being
+declared here — new telemetry cannot silently bypass the obs layer.
 
 Non-numeric leaves (strings such as shard ``device``, booleans such as
 ``quantized_scan``/``collective_query``, and ``None``) are outside the

@@ -11,6 +11,15 @@ The disabled path is :data:`NULL_TRACER` — a singleton whose
 ``span()`` returns one shared no-op context manager, so instrumented
 call sites cost a dict build and two trivial calls when tracing is
 off and never allocate span state.
+
+Both tracers also write every span into the JAX profiler's trace, as a
+host annotation named ``erarag.<name>``, whenever a profile is being
+captured (``jax.profiler.start_trace`` or a profiler server).  That
+copy carries the name only and lies on the profiler's clock, the one
+the device's events use, so a capture attributes the device's idle
+gaps to the layer the host was in; the recorded :class:`Span` keeps
+the obs clock and the attributes.  With no capture running, a span
+costs one extra ``TraceAnnotation.is_enabled()`` call.
 """
 from __future__ import annotations
 
@@ -19,7 +28,12 @@ import json
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import clock as _clock
+
+# prefix of the spans' names in a profiler capture
+PROFILER_PREFIX = "erarag."
 
 
 @dataclasses.dataclass
@@ -81,19 +95,21 @@ class Tracer:
             tid = self._next_trace
             self._next_trace += 1
             depth = 0
-        sp = Span(name=name, span_id=sid, parent_id=pid, trace_id=tid,
-                  start=self._clock(), depth=depth, attrs=dict(attrs))
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.end = self._clock()
-            self._stack.pop()
-            self.total_spans += 1
-            if len(self.spans) < self.max_spans:
-                self.spans.append(sp)
-            else:
-                self.dropped += 1
+        with profiled(name):
+            sp = Span(name=name, span_id=sid, parent_id=pid,
+                      trace_id=tid, start=self._clock(), depth=depth,
+                      attrs=dict(attrs))
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.end = self._clock()
+                self._stack.pop()
+                self.total_spans += 1
+                if len(self.spans) < self.max_spans:
+                    self.spans.append(sp)
+                else:
+                    self.dropped += 1
 
     def export_jsonl(self, path: str) -> int:
         """Write one JSON object per finished span; returns the count."""
@@ -128,8 +144,27 @@ class _NullContext:
 _NULL_CONTEXT = _NullContext()
 
 
+class _Annotation(TraceAnnotation):
+    """A profiler annotation that enters as ``None``, like a
+    :class:`NullTracer` span, so call sites that test for a recorded
+    span behave the same whether or not a profile is captured."""
+
+    def __enter__(self):
+        super().__enter__()
+        return None
+
+
+def profiled(name: str):
+    """``erarag.<name>`` in the profiler's trace while a profile is
+    captured; otherwise the shared no-op context."""
+    if TraceAnnotation.is_enabled():
+        return _Annotation(PROFILER_PREFIX + name)
+    return _NULL_CONTEXT
+
+
 class NullTracer:
-    """Inert tracer: no spans, no state, shared no-op context."""
+    """Inert tracer: no spans, no state; the shared no-op context, or
+    the profiler annotation alone while a profile is captured."""
 
     enabled = False
     total_spans = 0
@@ -137,7 +172,7 @@ class NullTracer:
     spans: tuple = ()
 
     def span(self, name: str, **attrs):
-        return _NULL_CONTEXT
+        return profiled(name)
 
     def export_jsonl(self, path: str) -> int:
         return 0

@@ -120,35 +120,31 @@ class Engine:
         self._prefix_cache: "OrderedDict[bytes, Tuple[Any, int]]" = \
             OrderedDict()
 
-        def _decode(params, tokens, caches, lengths):
-            """Per-slot decode: each slot has its own cache length."""
-            b = tokens.shape[0]
-            x = jnp.take(params["embed"], tokens, axis=0).astype(
-                ecfg.compute_dtype)
-            positions = lengths[:, None]                  # (b, 1)
-            x, _, new_caches = T._backbone(
-                params, x, cfg, positions, remat=False,
-                kv_caches=caches, cache_len=None,
-            )
-            x = T.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-            logits = T._logits(params, x, cfg)
-            return logits[:, -1], new_caches
-
         # bucketed prefill: batch dim fixed at max_batch, length padded
         # to the pow-2 bucket -> at most log2(max_seq_len) compiles
-        self._prefill_bucket = jax.jit(
-            lambda p, t, l: T.prefill_padded(
-                p, t, l, cfg, max_len=ecfg.max_seq_len,
-                compute_dtype=ecfg.compute_dtype))
-        self._decode_step = jax.jit(
-            lambda p, t, c, l: T.decode_step(
-                p, t, c, l, cfg, compute_dtype=ecfg.compute_dtype))
+        def engine_prefill(params, tokens, lengths):
+            return T.prefill_padded(params, tokens, lengths, cfg,
+                                    max_len=ecfg.max_seq_len,
+                                    compute_dtype=ecfg.compute_dtype)
+
+        def engine_decode(params, tokens, caches, length):
+            return T.decode_step(params, tokens, caches, length, cfg,
+                                 compute_dtype=ecfg.compute_dtype)
+
         # suffix prefill over per-row cache prefixes (prefix-cache hit
         # admission); compiles once per suffix bucket length
-        self._prefill_extend = jax.jit(
-            lambda p, t, l, o, c: T.prefill_extend(
-                p, t, l, o, c, cfg,
-                compute_dtype=ecfg.compute_dtype))
+        def engine_prefill_extend(params, tokens, lengths, offsets,
+                                  caches):
+            return T.prefill_extend(params, tokens, lengths, offsets,
+                                    caches, cfg,
+                                    compute_dtype=ecfg.compute_dtype)
+
+        # named functions give the programs stable names in the device
+        # trace: jit_engine_prefill, jit_engine_decode and
+        # jit_engine_prefill_extend
+        self._prefill_bucket = jax.jit(engine_prefill)
+        self._decode_step = jax.jit(engine_decode)
+        self._prefill_extend = jax.jit(engine_prefill_extend)
 
     # ------------------------------------------------------------------
     def submit(self, prompt: str, max_new_tokens: Optional[int] = None,
@@ -307,7 +303,8 @@ class Engine:
                 return old.at[:, dst].set(new[:, src])
 
             self.caches = jax.tree.map(scatter, self.caches, cache)
-            logits = np.asarray(logits)
+            with self.tracer.span("fetch"):
+                logits = np.asarray(logits)
             for j, (i, rid, ids, budget, pkey, plen) in \
                     enumerate(group):
                 if pkey is not None and \
@@ -372,7 +369,8 @@ class Engine:
             self.stats["prefix_hits"] += len(group)
             self.stats["prefix_tokens_saved"] += sum(
                 item[5] for item in group)
-            logits = np.asarray(logits)
+            with self.tracer.span("fetch"):
+                logits = np.asarray(logits)
             for i, rid, ids, budget, pkey, plen in group:
                 self.slots[i] = _Slot(
                     active=True, length=len(ids), budget=budget,
@@ -412,7 +410,9 @@ class Engine:
                                        new_caches)
             self.stats["decode_launches"] += 1
             self.stats["slot_steps"] += len(idxs)
-            logits = np.asarray(logits)
+            # the per-token sync: the host waits for the decode launch
+            with self.tracer.span("fetch"):
+                logits = np.asarray(logits)
             for i in idxs:
                 slot = self.slots[i]
                 nxt = int(np.argmax(logits[i]))

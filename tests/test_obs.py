@@ -257,7 +257,8 @@ def test_query_span_tree_and_ingest_stage_spans():
     assert kids == {"retrieve", "compose"}
     [ret] = [s for s in tr.spans if s.name == "retrieve"]
     rkids = {s.name for s in tr.children(ret)}
-    assert {"embed", "cache_lookup", "route", "scan"} <= rkids
+    assert {"embed", "cache_lookup", "route", "scan", "fetch", "hits",
+            "context"} <= rkids
     assert ret.attrs["epoch"] == rag.store.epoch
     [scan] = [s for s in tr.spans if s.name == "scan"]
     assert scan.attrs["epoch"] == rag.store.epoch
@@ -285,6 +286,111 @@ def test_engine_prefill_decode_spans():
     [comp] = [s for s in rag.obs.tracer.spans if s.name == "compose"]
     sub = {s.name for s in rag.obs.tracer.children(comp)}
     assert "prefill" in sub and "decode" in sub
+    # the host's wait for each launch's logits is its own span
+    assert "fetch" in sub
+
+
+@pytest.mark.parametrize("shards,collective,mode", [
+    (1, True, "collapsed"), (1, True, "detailed"),
+    (2, True, "collapsed"), (2, False, "collapsed")])
+def test_retrieve_span_tree_same_for_every_store_kind(shards, collective,
+                                                      mode):
+    """The host waiting on the scan (``fetch``), the ``Hit`` lists
+    (``hits``) and the budgeted composition (``context``) are direct
+    children of ``retrieve`` whatever the store, as ``route`` and
+    ``scan`` are."""
+    corpus = _corpus(n=8, seed=5)
+    rag = _rag(dataclasses.replace(CFG, obs_trace=True,
+                                   index_shards=shards,
+                                   collective_query=collective), corpus)
+    rag.query_batch([qa.question for qa in corpus.qa][:3], mode=mode)
+    tr = rag.obs.tracer
+    [ret] = [s for s in tr.spans if s.name == "retrieve"]
+    rkids = [s.name for s in tr.children(ret)]
+    assert {"embed", "route", "scan", "fetch", "hits", "context"} <= \
+        set(rkids)
+    # a store search ends with the fetch of its result, then its hits
+    for sp in tr.children(ret):
+        assert sp.start >= ret.start and sp.end <= ret.end
+    fetch = [s for s in tr.children(ret) if s.name == "fetch"][-1]
+    hits = [s for s in tr.children(ret) if s.name == "hits"][-1]
+    assert fetch.end <= hits.start
+
+
+# -- the spans in a profiler capture -----------------------------------
+def _capture(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler`` and return its result and the
+    capture's ``erarag.*`` host events as (name, start_ns, end_ns)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                       recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events.extend((ev.name, ev.start_ns,
+                           ev.start_ns + ev.duration_ns)
+                          for ev in line.events
+                          if ev.name.startswith("erarag."))
+    return out, events
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("obs_trace", [False, True])
+def test_spans_land_in_a_profiler_capture(tmp_path, obs_trace):
+    """With a capture running, one ``query_batch`` writes its spans as
+    ``erarag.*`` host events, nested as the spans nest: with the
+    recording tracer and with the default ``NULL_TRACER`` alike."""
+    corpus = _corpus(n=8, seed=5)
+    rag = _rag(dataclasses.replace(CFG, obs_trace=obs_trace), corpus)
+    qs = [qa.question for qa in corpus.qa][:3]
+    rag.query_batch(qs)                      # compile outside the capture
+    rag.obs.tracer.reset()
+    _, events = _capture(tmp_path, lambda: rag.query_batch(qs))
+    names = [n for n, _, _ in events]
+    for name in ("retrieve", "embed", "route", "scan", "fetch", "hits",
+                 "context"):
+        assert names.count("erarag." + name) == 1, (name, names)
+    by = {n: ev for ev in events for n in [ev[0]]}
+    for name in ("embed", "route", "scan", "fetch", "hits", "context"):
+        assert _inside(by["erarag." + name], by["erarag.retrieve"])
+    assert by["erarag.scan"][2] <= by["erarag.fetch"][1]
+    assert by["erarag.fetch"][2] <= by["erarag.hits"][1]
+    assert by["erarag.hits"][2] <= by["erarag.context"][1]
+    # the recording tracer records the same spans, on the obs clock
+    recorded = sorted("erarag." + sp.name for sp in rag.obs.tracer.spans)
+    assert recorded == (sorted(names) if obs_trace else [])
+
+
+def test_profiler_capture_leaves_answers_and_null_path_unchanged(
+        tmp_path):
+    """No capture: ``NULL_TRACER.span`` is the one shared no-op.  With
+    and without a capture the answers are bitwise equal."""
+    corpus = _corpus(n=8, seed=5)
+    rag = _rag(corpus=corpus)
+    qs = [qa.question for qa in corpus.qa][:4]
+    first = NULL_TRACER.span("retrieve", n=1)
+    assert NULL_TRACER.span("scan") is first
+    with first as sp:
+        assert sp is None
+    off = rag.query_batch(qs)
+    on, events = _capture(tmp_path, lambda: rag.query_batch(qs))
+    assert events and NULL_TRACER.span("scan") is first
+    key = [(r.hits, r.context, r.n_tokens, r.epoch) for r in off]
+    assert key == [(r.hits, r.context, r.n_tokens, r.epoch) for r in on]
+    assert rag.obs.tracer.total_spans == 0
 
 
 @pytest.mark.live

@@ -18,6 +18,7 @@ import pytest
 from repro.core.retrieve import adaptive_search_batch
 from repro.core.store import Hit
 from repro.data.tokenizer import EOS_ID
+from repro.obs import NULL_TRACER
 
 
 # ----------------------------------------------------------------------
@@ -98,6 +99,7 @@ class _TieStore:
     (seq 2), with identical scores — the merged order must be seq
     order regardless of which scan ran first."""
     epoch = 0
+    tracer = NULL_TRACER
 
     def search_batch(self, q, k, layer_filter=None):
         if layer_filter == "leaf":
