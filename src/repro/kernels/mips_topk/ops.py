@@ -19,6 +19,12 @@ process-scoped BY DESIGN — per-store attribution lives on each
 store's own ``StoreStats.kernel_launches``, so concurrently-live
 stores cannot bleed into each other's accounting (see
 ``tests/test_obs.py``).
+
+Beside it, ``kernels.mips_topk.padded_launches`` counts the launches
+whose static shapes make the Pallas scan pad its DB operand (a copy of
+the whole buffer) instead of reading it in place; the store's
+capacities (powers of two from its default 64) at its ``d + F`` width
+never do.
 """
 from __future__ import annotations
 
@@ -31,10 +37,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels.common import interpret_default, on_tpu
 from repro.kernels.mips_topk import ref
-from repro.kernels.mips_topk.kernel import mips_topk_pallas
+from repro.kernels.mips_topk.kernel import db_padded, mips_topk_pallas
 from repro.obs.metrics import global_registry
 
 _LAUNCHES = global_registry().counter("kernels.mips_topk.launches")
+_PADDED = global_registry().counter("kernels.mips_topk.padded_launches")
 
 
 def reset_launch_count() -> None:
@@ -44,6 +51,12 @@ def reset_launch_count() -> None:
 def launch_count() -> int:
     """Jitted launches dispatched from the host since the last reset."""
     return _LAUNCHES.count
+
+
+def _count_launch(n: int, d: int, use_pallas: bool | None) -> None:
+    _LAUNCHES.inc()
+    if (on_tpu() if use_pallas is None else use_pallas) and db_padded(n, d):
+        _PADDED.inc()
 
 
 @functools.partial(jax.jit, static_argnames=("k", "use_pallas",
@@ -66,7 +79,7 @@ def mips_topk(q: jnp.ndarray, db: jnp.ndarray, k: int, *,
               interpret: bool | None = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k inner products of each query row against the DB rows."""
-    _LAUNCHES.inc()
+    _count_launch(*db.shape, use_pallas)
     return _mips_topk(q, db, k, use_pallas=use_pallas,
                       interpret=interpret)
 
@@ -210,10 +223,10 @@ def sharded_mips_topk(q: jnp.ndarray, db_stacked: jnp.ndarray,
     ``k_out`` is capped by the store's live row count.  Returns merged
     ``(vals, seqs)``; the caller maps sequence numbers back to ids.
     """
-    s, cap, _ = db_stacked.shape
+    s, cap, width = db_stacked.shape
     assert k_shard <= cap and s * k_shard >= k_out, \
         (db_stacked.shape, k_shard, k_out)
-    _LAUNCHES.inc()
+    _count_launch(cap, width, use_pallas)
     return _sharded_mips_topk(
         q, db_stacked, seq_stacked, k_shard=int(k_shard),
         k_out=int(k_out), flag_bias=tuple(flag_bias), mesh=mesh,

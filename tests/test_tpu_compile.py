@@ -8,6 +8,7 @@ each.  The topology is described inside a fixture, never at import: a
 process that describes it holds the TPU library until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,6 @@ from jax.sharding import AxisType, Mesh, NamedSharding, \
 from repro.kernels.hamming_topk.kernel import hamming_topk_pallas
 from repro.kernels.lsh_hash.kernel import lsh_hash_pallas
 from repro.kernels.mips_topk import ops as mips_ops
-from repro.kernels.mips_topk.kernel import mips_topk_pallas
 
 
 @pytest.fixture(scope="module")
@@ -50,13 +50,29 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("b", [8, 64, 128])
-def test_mips_topk_compiles(one_chip, b):
+def _buffer_sized_ops(text: str, n: int) -> list:
+    """HLO ops, other than parameters and bitcasts, with ``n`` in their
+    result shape: a copy, pad or relayout of an ``(n, d)`` DB."""
+    shape = re.compile(rf"= f32\[[0-9,]*\b{n}\b[0-9,]*\]\S* (\w[\w-]*)\(")
+    return [m.group(0) for m in shape.finditer(text)
+            if m.group(1) not in ("parameter", "bitcast")]
+
+
+# the store's buffer, (cap, 768 + 3 flags), is column-major on the chip:
+# the scan reads it in place, with no pad, copy or temp buffer
+@pytest.mark.parametrize("b,n", [
+    pytest.param(8, 65_536, id="8"), pytest.param(64, 65_536, id="64"),
+    pytest.param(128, 65_536, id="128"), (1, 131_072), (16, 131_072),
+    (64, 131_072)])
+def test_mips_topk_compiles(one_chip, b, n):
     q = jax.ShapeDtypeStruct((b, 771), jnp.float32, sharding=one_chip)
-    db = jax.ShapeDtypeStruct((65_536, 771), jnp.float32,
-                              sharding=one_chip)
-    text = _compiled_text(lambda x, y: mips_topk_pallas(x, y, 8), q, db)
+    db = jax.ShapeDtypeStruct((n, 771), jnp.float32, sharding=one_chip)
+    compiled = mips_ops._mips_topk.lower(
+        q, db, 8, use_pallas=True, interpret=False).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert _buffer_sized_ops(text, n) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("n,d", [(4096, 768), (13, 256)])
@@ -86,10 +102,12 @@ def test_sharded_mips_topk_compiles(topo):
     seq = jax.ShapeDtypeStruct((4, 16_384), jnp.int32,
                                sharding=NamedSharding(mesh,
                                                       P("data", None)))
-    text = mips_ops._sharded_mips_topk.lower(
+    compiled = mips_ops._sharded_mips_topk.lower(
         q, db, seq, k_shard=8, k_out=8,
         flag_bias=(mips_ops.MASK_BIAS, 0.0, 0.0), mesh=mesh,
-        axis_names=("data",), use_pallas=True,
-        interpret=False).compile().as_text()
+        axis_names=("data",), use_pallas=True, interpret=False).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+    # each local slot is scanned in place: no slot-sized copy or pad
+    assert compiled.memory_analysis().temp_size_in_bytes < 16_384 * 771 * 4
