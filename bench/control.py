@@ -7,11 +7,15 @@ configuration's, in the program's place), in one process.
     python3 bench/control.py --workload ingest_lm --seconds 5 \\
         --seeds 11,12,13 [--dump DIR]
 
-Prints one JSON line per seed; ``--dump`` also writes the per-position
-arrays the LM numbers are taken from (served-token gaps of the program
-and of the control, and the reference's top-two margins) to
-``DIR/<workload>.<seed>.npz``.  The benchmark's own runs never run the
-control.  Needs the chip, as ``run.py`` does.
+Prints one JSON line per seed: the numbers of the program and of the
+control, and each side's verdict under the configuration's ``limits``
+(``correct``, with every number compared beside its limit), the
+comparison ``run.py`` makes.  It exits 1 where a seed reads the
+program not correct or the control correct.  ``--dump`` also writes
+the per-position arrays the LM numbers are taken from (served-token
+gaps of the program and of the control, and the reference's top-two
+margins) to ``DIR/<workload>.<seed>.npz``.  The benchmark's own runs
+never run the control.  Needs the chip, as ``run.py`` does.
 """
 from __future__ import annotations
 
@@ -28,11 +32,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from bench import common as C  # noqa: E402
 
 
-def readings(workload: str, seeds, seconds: float, *, bench=None,
-             configs=None, traffics=None, cache_dir=C.CACHE):
-    """Yield (seed, readings, arrays) for each seed; ``arrays`` are the
-    cell's per-position readings, where it keeps any."""
-    bench = bench or C.benchmark()
+def readings(workload: str, seeds, seconds: float, *, configs=None,
+             traffics=None, cache_dir=C.CACHE):
+    """Yield (seed, readings, verdict, arrays) for each seed; ``verdict``
+    is ``verdict(readings, limits)``, ``arrays`` the cell's
+    per-position readings, where it keeps any."""
+    bench = C.benchmark()
     w = C.workload(workload, bench)
     cfg = (configs or {}).get(w["config"]) or C.config(w["config"], bench)
     traffic = (traffics or {}).get(w["traffic"]) or C.traffic(w["traffic"])
@@ -44,9 +49,23 @@ def readings(workload: str, seeds, seconds: float, *, bench=None,
         cell.window(seconds)
         cell.release()
         r = cell.readings()
-        yield seed, r, getattr(cell, "gap_arrays", {})
+        yield seed, r, verdict(r, cfg["limits"]), \
+            getattr(cell, "gap_arrays", {})
         del cell
         gc.collect()
+
+
+def verdict(readings: dict, limits: dict) -> dict:
+    """For the program and for the control, ``correct`` and each number
+    that has a limit beside it: correct where none is over its limit."""
+    out = {}
+    for side, numbers in readings.items():
+        checks = {n: {"value": numbers[n], "limit": lim}
+                  for n, lim in limits.items() if n in numbers}
+        out[side] = {"correct": all(c["value"] <= c["limit"]
+                                    for c in checks.values()),
+                     "checks": checks}
+    return out
 
 
 def main(argv=None) -> int:
@@ -62,15 +81,21 @@ def main(argv=None) -> int:
         C.log("no chip")
         return 3
     C.enable_compile_cache()
-    for seed, r, arrays in readings(
+    code = 0
+    for seed, r, v, arrays in readings(
             args.workload, [int(s) for s in args.seeds.split(",")],
             args.seconds):
-        print(json.dumps({"seed": seed, **r}), flush=True)
+        print(json.dumps({"seed": seed, **r, "verdict": v}), flush=True)
+        for side, want in (("program", True), ("control", False)):
+            C.log(f"seed {seed}: {side} correct {v[side]['correct']}, "
+                  f"{v[side]['checks']}")
+            if v[side]["correct"] is not want:
+                code = 1
         if args.dump and arrays:
             Path(args.dump).mkdir(parents=True, exist_ok=True)
             np.savez(Path(args.dump) / f"{args.workload}.{seed}.npz",
                      **arrays)
-    return 0
+    return code
 
 
 if __name__ == "__main__":

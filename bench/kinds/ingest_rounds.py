@@ -8,17 +8,20 @@ the device, so the document is searchable.  Rounds run back to back;
 the window closes at the end of the round in progress when
 ``--seconds`` runs out, so no round is cut.
 
-Traffic parameters (``traffic/<name>.json``): ``docs_per_round`` and
-``warm_rows``.  The held-out documents are taken in the order the
-configuration's corpus lists them, whatever the seed, so that every
-seed starts from the same insert; the seed draws the LM's weights, and
-through its summaries how far the upper layers re-partition.
+Traffic parameters (``traffic/<name>.json``): ``docs_per_round``,
+``warm_rows``, and ``trace_start_s`` and ``trace_seconds``, the slice
+of the window a traced run traces.  The held-out documents are taken in the
+order the configuration's corpus lists them, whatever the seed, so
+that every seed starts from the same insert; the seed draws the LM's
+weights, and through its summaries how far the upper layers
+re-partition.
 
 ``correct`` compares what the window served (see ``check``).
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from typing import Callable, Dict, List
 
@@ -26,9 +29,8 @@ import numpy as np
 
 from bench.common import log
 from bench.index import open_index
-from bench.lm import Recorder, lm_config, make_params, shape
+from bench.lm import Recorder, arch, make_params
 from bench.reference import index as rix
-from bench.reference import qwen2 as rq
 from bench.reference import text as rt
 
 PREFIX = ("Summarize the following passages into one coherent "
@@ -77,13 +79,16 @@ class Cell:
         from repro.serving.engine import Engine, EngineConfig
 
         cfg = self.cfg
+        self.arch = arch(cfg["model_type"])
+        self.shape = self.arch.model.shape(cfg)
         self.rag, held_out, _ = open_index(cfg, self.cache_dir)
         self.base_ids = set(self.rag.graph.nodes)
         self.pool = list(held_out)
         sv = cfg["serving"]
-        self.params = make_params(cfg, self.seed)
+        self.params = make_params(self.arch.model.weights, cfg, self.seed)
         jax.block_until_ready(self.params)
-        self.engine = Engine(lm_config(cfg), self.params, EngineConfig(
+        lm = self.arch.model.lm_config(cfg)
+        self.engine = Engine(lm, self.params, EngineConfig(
             max_batch=sv["max_batch"], max_seq_len=sv["max_seq_len"],
             compute_dtype=jnp.bfloat16))
         self.rec = Recorder(self.engine)
@@ -129,9 +134,13 @@ class Cell:
 
     # ------------------------------------------------------------------
     def window(self, seconds: float,
-               annotate: Callable = contextlib.nullcontext) -> dict:
+               annotate: Callable = contextlib.nullcontext,
+               tick: Callable = None) -> dict:
         import jax
         rag = self.rag
+        self.at_trace = {}
+        if tick is not None:
+            self._tick_each_step(tick)
         t0 = time.perf_counter()
         i = 0
         while True:
@@ -154,18 +163,42 @@ class Cell:
         return {"ingest_chunks_per_s": chunks / self.elapsed,
                 "attempted": len(self.reports), "failed": 0}
 
+    def _tick_each_step(self, tick: Callable) -> None:
+        """Call ``tick`` after every engine step; where it starts or
+        stops the trace, keep what the LM had served by then."""
+        step = self.engine.step
+
+        def ticking_step():
+            n = step()
+            event = tick()
+            if event:
+                self.at_trace[event] = self.served(in_flight=True)
+            return n
+
+        self.engine.step = ticking_step
+
+    def served(self, in_flight: bool = False) -> List[tuple]:
+        """(prompt tokens, tokens out) of each request the LM finished,
+        and with ``in_flight`` of each in a slot, as far as it got."""
+        reqs = [(p, b, len(t)) for _, p, b, t in self.rec.finished()]
+        if in_flight:
+            reqs += [(*self.rec.prompts[s.request_id], len(s.out_tokens))
+                     for s in self.engine.slots if s.active]
+        sv = self.cfg["serving"]
+        return [(len(rt.served_prompt(p, self.cfg["vocab_size"], b,
+                                      sv["max_seq_len"])), n)
+                for p, b, n in reqs]
+
     def counters(self) -> dict:
+        """The engine's counters and the graph's over the window; the
+        LM's operations over the traced part of it."""
         st = self.engine.stats
         d = {k: st[k] - self.stats0[k] for k in st}
-        served = []
-        sv = self.cfg["serving"]
-        vocab = self.cfg["vocab_size"]
-        for _, prompt, budget, toks in self.rec.finished():
-            ids = rt.served_prompt(prompt, vocab, budget,
-                                   sv["max_seq_len"])
-            served.append((len(ids), len(toks)))
+        served = self.served()
+        flops = functools.partial(self.arch.flops.total_flops, self.shape)
         return {"engine": d, "served": served,
-                "shape": shape(self.cfg),
+                "lm_flops": flops(self.at_trace.get("stop", served))
+                - flops(self.at_trace.get("start", [])),
                 "chunks": sum(r.n_new_chunks for r in self.reports),
                 "tokens_in": sum(r.tokens_in for r in self.reports),
                 "tokens_out": sum(r.tokens_out for r in self.reports)}
@@ -187,8 +220,9 @@ class Cell:
             ("graph_violations", self.graph_violations(), 0)]
 
     def readings(self) -> dict:
-        """The LM numbers of the program and of the int8 control, on
-        the same sample of served requests."""
+        """The LM numbers of the program and of the control (the
+        reference's forward one precision step below the
+        configuration's), on the same sample of served requests."""
         return self.lm_numbers(control=True)
 
     def sample(self) -> List[tuple]:
@@ -211,11 +245,11 @@ class Cell:
     def lm_numbers(self, control: bool = False) -> dict:
         """Over the sampled requests' served tokens, how far each lies
         below the float32 reference's best logit (``_gap_numbers``).
-        With ``control``, the same of the tokens the int8 forward puts
-        first, at the same positions."""
+        With ``control``, the same of the tokens the control's forward
+        puts first, at the same positions."""
         t = time.perf_counter()
-        shp = shape(self.cfg)
-        per = [rq.served_gaps(self.params, shp, prompt, toks, control)
+        gaps = self.arch.reference.served_gaps
+        per = [gaps(self.params, self.shape, prompt, toks, control)
                for prompt, toks in self.sample()]
         a = {k: np.concatenate([r[k] for r in per] or [np.zeros(0)])
              for k in ("gap", "margin", "control")

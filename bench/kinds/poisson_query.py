@@ -101,7 +101,7 @@ class Cell:
 
     def window(self, seconds: float,
                annotate: Callable = contextlib.nullcontext,
-               rate: float = None) -> dict:
+               rate: float = None, tick: Callable = None) -> dict:
         tr = self.traffic
         rate = rate or tr["rate_per_s"]
         arrive, which = schedule(rate, seconds, len(self.pool), self.seed)
@@ -147,6 +147,8 @@ class Cell:
             self.sizes.append(end - nxt)
             nxt = end
             batches += 1
+            if tick is not None:
+                tick()
         self.elapsed = time.perf_counter() - t0
         pauses.close()
         # an unanswered question misses every latency limit: it counts

@@ -1,88 +1,63 @@
-"""The configuration's LM: weights made on the device from the seed,
-in the type they are served in, and the program's engine over them.
+"""The configuration's LM: its architecture's modules, found by the
+configuration's ``model_type``; weights made on the device from the
+seed, in the type they are served in; and a recorder of what the
+program's engine served.
 
-The weights are the benchmark's, not the program's: one jitted call
-draws every tensor in the layout the program's transformer reads, so
-the reference (``reference/qwen2.py``) and the program start from the
-same numbers and nothing of the program's own initialisation.
+An architecture is three files named after its ``model_type``, and
+nothing else of the harness names it:
+
+- ``models/<model_type>.py``: ``shape(cfg)``, the widths the other two
+  read; ``lm_config(cfg)``, the program's ``LMConfig``; and
+  ``weights(cfg, key)``, every weight in the layout the program's
+  transformer reads, traced inside one jitted call;
+- ``reference/<model_type>.py``: ``served_gaps(params, shape, prompt,
+  served, control)``, the plain float32 forward's verdict on served
+  tokens, and the control one precision step below the configuration's;
+- ``flops/<model_type>.py``: ``total_flops(shape, requests)``.
+
+The weights are the benchmark's, not the program's, so the reference
+and the program start from the same numbers and nothing of the
+program's own initialisation.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+import functools
+import importlib
+from typing import Dict, List, NamedTuple, Tuple
 
 import jax
-import jax.numpy as jnp
+
+from bench.common import BENCH, ROOT
+
+PARTS = ("models", "reference", "flops")
 
 
-def shape(cfg: dict) -> dict:
-    """The published widths the flops and the reference read."""
-    out = {k: cfg[k] for k in (
-        "hidden_size", "intermediate_size", "num_attention_heads",
-        "num_key_value_heads", "num_hidden_layers", "vocab_size",
-        "rope_theta", "rms_norm_eps")}
-    out["head_dim"] = cfg["hidden_size"] // cfg["num_attention_heads"]
-    return out
+class Arch(NamedTuple):
+    model: object
+    reference: object
+    flops: object
 
 
-def lm_config(cfg: dict):
-    from repro.common.config import LMConfig
-    s = shape(cfg)
-    return LMConfig(
-        name=cfg["name"], family="lm-dense", source=cfg["source"],
-        n_layers=s["num_hidden_layers"], d_model=s["hidden_size"],
-        n_heads=s["num_attention_heads"],
-        n_kv_heads=s["num_key_value_heads"], d_head=s["head_dim"],
-        d_ff=s["intermediate_size"], vocab_size=s["vocab_size"],
-        rope_theta=float(s["rope_theta"]), qkv_bias=True,
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        norm_eps=float(s["rms_norm_eps"]),
-        max_seq_len=cfg["max_position_embeddings"])
+def arch(model_type: str) -> Arch:
+    """The modules of ``model_type``; a type without all three stops
+    the run and names the files to add."""
+    paths = [BENCH / part / f"{model_type}.py" for part in PARTS]
+    missing = [str(p.relative_to(ROOT)) for p in paths if not p.is_file()]
+    if missing:
+        raise SystemExit(
+            f"no benchmark modules for model_type {model_type!r}: add "
+            f"{', '.join(missing)} (see bench/lm.py)")
+    return Arch(*(importlib.import_module(f"bench.{part}.{model_type}")
+                  for part in PARTS))
 
 
-def make_params(cfg: dict, seed: int):
-    """Every weight from ``seed`` in one jitted program, bfloat16."""
-    s = shape(cfg)
-    L, d, h, kv, dh, ff, V = (s["num_hidden_layers"], s["hidden_size"],
-                              s["num_attention_heads"],
-                              s["num_key_value_heads"], s["head_dim"],
-                              s["intermediate_size"], s["vocab_size"])
-    dt = jnp.bfloat16
-
-    def build(key):
-        ks = iter(jax.random.split(key, 16))
-
-        def mat(shp, fan_in):
-            return (jax.random.normal(next(ks), shp, jnp.float32)
-                    * fan_in ** -0.5).astype(dt)
-
-        def near(shp, mean):
-            return (mean + 0.02 * jax.random.normal(next(ks), shp,
-                                                    jnp.float32)).astype(dt)
-
-        layer = {
-            "attn": {"wq": mat((L, d, h * dh), d),
-                     "wk": mat((L, d, kv * dh), d),
-                     "wv": mat((L, d, kv * dh), d),
-                     "wo": mat((L, h * dh, d), h * dh),
-                     "bq": near((L, h * dh), 0.0),
-                     "bk": near((L, kv * dh), 0.0),
-                     "bv": near((L, kv * dh), 0.0)},
-            "ffn": {"w_gate": mat((L, d, ff), d),
-                    "w_up": mat((L, d, ff), d),
-                    "w_down": mat((L, ff, d), ff)},
-            "ln1": near((L, d), 1.0),
-            "ln2": near((L, d), 1.0),
-        }
-        params = {"embed": mat((V, d), 1), "layers": (layer,),
-                  "final_norm": near((d,), 1.0)}
-        if not cfg["tie_word_embeddings"]:
-            params["lm_head"] = mat((d, V), d)
-        return params
-
+def make_params(weights, cfg: dict, seed: int):
+    """Every weight from ``seed`` in one jitted program; ``weights`` is
+    the architecture's ``weights(cfg, key)``."""
     # a seed of more than 32 bits is folded in word by word
     key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
     key = jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
-    return jax.jit(build)(key)
+    return jax.jit(functools.partial(weights, cfg))(key)
 
 
 class _Sink(dict):

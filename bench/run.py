@@ -46,16 +46,15 @@ def parse(argv=None):
     return ap.parse_args(argv)
 
 
-def run(args, *, bench=None, configs=None, traffics=None,
-        cache_dir=C.CACHE,
+def run(args, *, configs=None, traffics=None, cache_dir=C.CACHE,
         require_tpu=True, peaks=None, patch=None, compile_cache=True,
         t_start=T_START):
     """One cell; returns (exit code, result dict or None).  The keyword
-    arguments are for the harness's own tests: a benchmark file,
-    configurations and traffic of their own, a cache directory, no chip, the
-    peaks to divide by, ``patch(cell)``, called after set-up, to break
-    the timed path, and no persistent compile cache."""
-    bench = bench or C.benchmark()
+    arguments are for the harness's own tests: configurations and
+    traffic of their own, a cache directory, no chip, the peaks to
+    divide by, ``patch(cell)``, called after set-up, to break the timed
+    path, and no persistent compile cache."""
+    bench = C.benchmark()
     w = C.workload(args.workload, bench)
     cfg = (configs or {}).get(w["config"]) or C.config(w["config"], bench)
     traffic = (traffics or {}).get(w["traffic"]) or C.traffic(w["traffic"])
@@ -86,11 +85,13 @@ def run(args, *, bench=None, configs=None, traffics=None,
     C.log(f"setup: {setup_s:.3f} s, {setup_compiles[0]} backend compiles "
           f"({setup_compiles[1]:.3f} s)")
 
-    prof = Profiler(Path(cache_dir) / "trace") if args.trace else None
+    prof = Profiler(Path(cache_dir) / "trace",
+                    traffic.get("trace_start_s", 0.0),
+                    traffic.get("trace_seconds")) if args.trace else None
     if prof:
         prof.start()
-    with annotate("bench.window"):
-        e2e = cell.window(args.seconds, annotate)
+    e2e = cell.window(args.seconds, annotate,
+                      tick=prof.tick if prof else None)
     red = prof.stop() if prof else None
     C.log(f"compiles inside the window: {compiles.n - setup_compiles[0]} "
           f"({compiles.seconds - setup_compiles[1]:.3f} s)")

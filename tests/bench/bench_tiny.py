@@ -27,21 +27,10 @@ LIMITS = {"lm_tie_gap": 0.0018, "score_err": 6e-7}
 SEED = 1
 
 
-def bench() -> dict:
-    """``BENCHMARK.json`` with the entries of the cells held out of it
-    (``bench/held/*.json``), whose harness is tested all the same."""
-    out = C.benchmark()
-    for p in sorted((C.BENCH / "held").glob("*.json")):
-        held = C.load_json(p)
-        for key in ("configs", "workloads", "end_to_end", "per_layer"):
-            out[key] = held[key] + out[key]
-    return out
-
-
 def configs() -> dict:
     """The benchmark's configurations at tiny sizes (same keys)."""
     out = {}
-    for c in bench()["configs"]:
+    for c in C.benchmark()["configs"]:
         cfg = copy.deepcopy(C.load_json(C.ROOT / c["file"]))
         cfg["corpus_docs"] = 48
         cfg["index"].update(embed_dim=64, held_out_docs=8,
@@ -60,10 +49,11 @@ def configs() -> dict:
 
 def traffics() -> dict:
     out = {}
-    for w in bench()["workloads"]:
+    for w in C.benchmark()["workloads"]:
         t = copy.deepcopy(C.traffic(w["traffic"]))
         if t["kind"] == "ingest_rounds":
-            t["warm_rows"] = 2
+            # a tiny round is over before the real trace slice starts
+            t.update(warm_rows=2, trace_start_s=0.0)
         else:
             t.update(rate_per_s=200.0, max_batch=4)
         out[w["traffic"]] = t
@@ -92,7 +82,7 @@ def run_tiny(workload: str, tmp_path, seed: int = SEED, seconds: float = 0.5,
     run = _bench_run()
     args = run.parse(["--workload", workload, "--seed", str(seed),
                       "--seconds", str(seconds), "--trace", str(trace)])
-    return run.run(args, bench=bench(), configs=configs(),
+    return run.run(args, configs=configs(),
                    traffics=traffics(),
                    cache_dir=Path(tmp_path), require_tpu=False,
                    peaks=PEAKS, patch=patch, compile_cache=False)

@@ -8,7 +8,7 @@ import pytest
 
 import bench_tiny as T
 from bench import common as C
-from bench.lm import lm_config, make_params
+from bench.lm import arch, make_params
 
 BIG = 2**31 + 12345
 
@@ -67,16 +67,18 @@ def test_ingest_documents_are_the_same_for_every_seed():
 
 def test_weights_follow_the_seed_and_have_the_program_layout():
     cfg = T.configs()["era768_qwen2-7b-l16"]
-    a = make_params(cfg, BIG)
-    b = make_params(cfg, BIG)
-    c = make_params(cfg, BIG + 2**32)   # differs above the low word
+    lm_arch = arch(cfg["model_type"])
+    a = make_params(lm_arch.model.weights, cfg, BIG)
+    b = make_params(lm_arch.model.weights, cfg, BIG)
+    c = make_params(lm_arch.model.weights, cfg, BIG + 2**32)   # high word
     la, lb, lc = (jax.tree.leaves(x) for x in (a, b, c))
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
     assert not np.array_equal(la[0], lc[0])
     assert all(x.dtype == jnp.bfloat16 for x in la)
     from repro.models import transformer as TR
+    lm = lm_arch.model.lm_config(cfg)
     want = jax.eval_shape(
-        lambda: TR.init_params(lm_config(cfg), jax.random.PRNGKey(0),
+        lambda: TR.init_params(lm, jax.random.PRNGKey(0),
                                dtype=jnp.bfloat16)[0])
     got = jax.eval_shape(lambda: a)
     assert jax.tree.structure(got) == jax.tree.structure(want)

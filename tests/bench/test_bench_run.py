@@ -26,7 +26,7 @@ def test_result_line(workload, trace, bench_cache):
     assert res["attempted"] > 0 and res["failed"] == 0
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= \
         set(res["device"])
-    bench = T.bench()
+    bench = C.benchmark()
     if trace:
         assert {"busy_s", "window_s"} <= set(res["device"])
         assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
@@ -48,6 +48,30 @@ def test_result_line(workload, trace, bench_cache):
         assert c["value"] <= c["limit"]
 
 
+@pytest.mark.parametrize("start_s", [0.0, 0.2])
+def test_a_traced_run_of_part_of_the_ingest_window(bench_cache,
+                                                   monkeypatch, start_s):
+    """A traced slice of the window starts and stops at engine steps:
+    the LM's operations are those served between the two, a part of the
+    window's, and the run is checked as a whole."""
+    traffics = T.traffics()
+    traffics["single_doc_rounds"].update(trace_start_s=start_s,
+                                         trace_seconds=0.0)
+    monkeypatch.setattr(T, "traffics", lambda: traffics)
+    cells = []
+    code, res = T.run_tiny(T.INGEST, bench_cache, trace=1,
+                           patch=cells.append)
+    assert code == 0 and res["correct"] is True, res["checks"]
+    cell = cells[0]
+    assert set(cell.at_trace) == ({"stop"} if start_s == 0
+                                  else {"start", "stop"})
+    tokens = [sum(n for _, n in cell.at_trace.get(k, []))
+              for k in ("start", "stop")]
+    assert tokens[0] < tokens[1] < sum(n for _, n in cell.served())
+    assert res["metrics"]["lm_mfu.ingest"]["value"] > 0
+    assert res["device"]["window_s"] < cell.elapsed
+
+
 def test_refuses_without_a_chip():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
@@ -60,9 +84,19 @@ def test_refuses_without_a_chip():
     assert "no chip" in out.stderr
 
 
+def test_every_cell_runs_from_the_benchmark_file():
+    """The tiny runs drive the cells as ``BENCHMARK.json`` lists them:
+    no cell is held in a file of its own.  Later cells join as entries
+    and files, so only these two are asked for."""
+    assert not (C.BENCH / "held").exists()
+    cells = {w["name"]: w["config"] for w in C.benchmark()["workloads"]}
+    assert cells.items() >= {T.INGEST: "era768_qwen2-7b-l16",
+                             T.RETRIEVE: "era768_index"}.items()
+
+
 def test_cells_metrics_and_mixes_are_found_by_name():
-    bench = T.bench()
-    assert {w["name"] for w in bench["workloads"]} == {T.INGEST,
+    bench = C.benchmark()
+    assert {w["name"] for w in bench["workloads"]} >= {T.INGEST,
                                                       T.RETRIEVE}
     for w in bench["workloads"]:
         traffic = C.traffic(w["traffic"])
@@ -77,7 +111,9 @@ def test_cells_metrics_and_mixes_are_found_by_name():
                                "config": "era768_index",
                                "traffic": "poisson_qa", "chips": 1,
                                "why": "x"})
-    extra["end_to_end"][1]["workloads"].append("retrieve_fast")
+    next(m for m in extra["end_to_end"]
+         if m["name"] == "retrieve_p95_ms")["workloads"].append(
+        "retrieve_fast")
     extra["per_layer"].append({"name": "device_idle.retrieve_fast",
                                "unit": "%", "better": "lower",
                                "source": "device_trace", "layer": "device",

@@ -34,6 +34,12 @@ def test_time_by_name_top_names_and_gap_labels():
     assert T.label(200, spans) == "untraced"
 
 
+def test_the_profiler_leaves_the_python_tracer_off():
+    opts = T.profile_options()
+    assert opts.python_tracer_level == 0
+    assert opts.host_tracer_level == 1
+
+
 def test_reads_a_trace_recorded_on_the_cpu(tmp_path):
     import jax
     import jax.numpy as jnp
@@ -45,12 +51,11 @@ def test_reads_a_trace_recorded_on_the_cpu(tmp_path):
     x = jnp.ones((256, 256))
     step(x).block_until_ready()
     prof = T.Profiler(tmp_path / "trace")
-    prof.start()
-    with jax.profiler.TraceAnnotation("bench.window"):
-        for _ in range(3):
-            with jax.profiler.TraceAnnotation("bench.step"):
-                step(x).block_until_ready()
-            time.sleep(0.02)
+    prof.start()                        # opens bench.window
+    for _ in range(3):
+        with jax.profiler.TraceAnnotation("bench.step"):
+            step(x).block_until_ready()
+        time.sleep(0.02)
     # on the CPU the programs run on the host plane's client threads
     red = prof.stop(device_prefix="/host:CPU",
                     module_line="tf_XLAPjRtCpuClient",
@@ -66,3 +71,59 @@ def test_reads_a_trace_recorded_on_the_cpu(tmp_path):
     bd = red.breakdown()
     assert len(bd["idle_gaps"]) <= 10 and bd["idle_gaps"]
     assert {k for k, _ in bd["idle_gaps"]} <= {"bench.step", "bench.window"}
+
+
+@pytest.mark.parametrize("start_s", [0.0, 0.05])
+def test_a_trace_of_a_slice_of_the_window(tmp_path, start_s):
+    """With ``start_s`` and ``seconds``, the first ``tick`` past the one
+    starts the trace and the first past both stops it; what the cell
+    does before and after is not in it."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def step(x):
+        return jnp.tanh(x @ x).sum()
+
+    x = jnp.ones((128, 128))
+    step(x).block_until_ready()
+    prof = T.Profiler(tmp_path / "trace", start_s=start_s, seconds=0.1)
+    prof.start()
+    ticks = []
+    for _ in range(60):
+        with jax.profiler.TraceAnnotation("bench.step"):
+            step(x).block_until_ready()
+        time.sleep(0.01)
+        ticks.append(prof.tick())
+    events = [t for t in ticks if t]
+    assert events == (["stop"] if start_s == 0 else ["start", "stop"])
+    first = ticks.index("start") + 1 if start_s else 0
+    last = ticks.index("stop")
+    # steps wholly inside the trace: those after the starting tick, up
+    # to the one whose tick stopped it
+    inside = last + 1 - first
+    assert 5 <= inside < 60
+    red = prof.stop(device_prefix="/host:CPU",
+                    module_line="tf_XLAPjRtCpuClient",
+                    op_line="tf_XLAEigen")
+    # the annotation opens just after the profiler's clock is read
+    assert 0.09 <= red.window_s < 0.1 + 0.5
+    assert [n for n, _, _ in red.host].count("bench.step") == inside
+    assert not (tmp_path / "trace").exists()
+
+
+def test_a_trace_without_seconds_covers_the_whole_window(tmp_path):
+    prof = T.Profiler(tmp_path / "trace")
+    prof.start()
+    time.sleep(0.02)
+    assert prof.tick() is None
+    red = prof.stop(device_prefix="/host:CPU")
+    assert red.window_s >= 0.02
+
+
+def test_a_window_that_ends_before_its_trace_starts(tmp_path):
+    prof = T.Profiler(tmp_path / "trace", start_s=60.0, seconds=1.0)
+    prof.start()
+    assert prof.tick() is None
+    with pytest.raises(RuntimeError, match="before 60.0 s"):
+        prof.stop()
